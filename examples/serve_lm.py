@@ -1,98 +1,23 @@
-"""End-to-end serving driver: batched LM inference through the platform.
+"""Batched LM inference through the platform, at the reduced config on CPU.
 
-Client requests enter a Dandelion composition whose compute function is a
-*prefill+decode generation call* against the continuous-batching engine -
-i.e. the model is the payload and the platform owns admission, fan-out,
-memory contexts, and engine scheduling. The generation call is declared
-through the SDK (``sdk.declare``; ``memoize=False`` because the batcher
-is stateful) and driven through a single-node Platform's handle API.
-Any of the 10 assigned architectures is selectable with --arch (reduced
-config on CPU).
+A thin caller of ``repro.launch.serve``: client requests enter the
+``serve_lm`` composition, whose ``generate`` vertex drives the
+continuous-batching engine, so the model is the payload and the platform
+owns admission, memory contexts and engine scheduling. Any of the
+assigned architectures is selectable with --arch; every other option of
+``repro.launch.serve`` is accepted too and overrides the defaults below.
+Exits non-zero unless every request finished.
 
     PYTHONPATH=src python examples/serve_lm.py --arch olmoe-1b-7b --requests 12
 """
-import argparse
-import time
+import sys
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+from repro.launch.serve import main
 
-from repro import sdk
-from repro.configs import ARCH_IDS, get_smoke
-from repro.core import Item
-from repro.models.model import build as build_model
-from repro.serving.batching import ContinuousBatcher, Request
-
-
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="granite-8b", choices=ARCH_IDS)
-    ap.add_argument("--requests", type=int, default=12)
-    ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--slots", type=int, default=4)
-    args = ap.parse_args()
-
-    cfg = get_smoke(args.arch)
-    api = build_model(cfg)
-    params = api.init_params(jax.random.PRNGKey(0))
-    print(f"arch={cfg.name} ({api.param_count()/1e6:.1f}M params)")
-
-    def extras_fn(rid):
-        if cfg.family == "encdec":
-            return {"frames": jnp.zeros((1, 16, cfg.d_model), jnp.bfloat16)}
-        if cfg.family == "vlm":
-            return {"patches": jnp.zeros((1, cfg.num_patches or 8, cfg.d_model), jnp.bfloat16)}
-        return {}
-
-    batcher = ContinuousBatcher(api, params, num_slots=args.slots,
-                                cache_len=32, extras_fn=extras_fn)
-    rid_counter = [0]
-
-    # the generation call is a pure compute function: prompt ids in,
-    # generated ids out - the platform cold-starts a context per request
-    def generate_fn(inputs):
-        prompt = list(np.frombuffer(inputs["prompt"][0].data, np.int32))
-        rid_counter[0] += 1
-        rid = rid_counter[0]
-        batcher.submit(Request(rid, prompt, max_new_tokens=args.max_new))
-        out = batcher.run_to_completion()[rid]
-        return {"tokens": [Item(np.asarray(out, np.int32).tobytes())]}
-
-    generate = sdk.declare(
-        "generate", generate_fn, inputs=("prompt",), outputs=("tokens",),
-        context_bytes=8 << 20, memoize=False,
-        # knowingly impure: drives the stateful continuous batcher and a
-        # closed-over request counter — real serving, not a modeled payload
-        pure_unsafe=True,
-    )
-    with sdk.composition("serve_lm") as app:
-        g = generate(prompt=app.input("prompt"))
-        app.output("tokens", g.tokens)
-
-    platform = sdk.Platform(node=sdk.NodeSpec(num_slots=4, comm_slots=1))
-    platform.deploy(app)
-
-    rng = np.random.default_rng(0)
-    handles = []
-    t0 = time.time()
-    for i in range(args.requests):
-        plen = int(rng.integers(3, 12))
-        prompt = rng.integers(0, cfg.vocab_size, plen, dtype=np.int32)
-        handles.append(platform.invoke(
-            app, {"prompt": [Item(prompt.tobytes())]}, at=i * 1e-3))
-    platform.run()
-    wall = time.time() - t0
-
-    ok = [h for h in handles if h.done]
-    toks = sum(len(np.frombuffer(h.outputs["tokens"][0].data, np.int32)) for h in ok)
-    print(f"served {len(ok)}/{args.requests} requests, {toks} tokens, "
-          f"{wall:.2f}s wall ({toks/wall:.1f} tok/s)")
-    print("platform latency (virtual):",
-          {k: round(v, 3) for k, v in platform.latency.summary().items()})
-    for h in ok[:3]:
-        print("  ->", np.frombuffer(h.outputs["tokens"][0].data, np.int32).tolist())
-
+SMOKE_DEFAULTS = [
+    "--smoke", "--arch", "granite-8b", "--requests", "12", "--max-new", "8",
+    "--cache-len", "32", "--min-prompt", "3", "--max-prompt", "11",
+]
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main(SMOKE_DEFAULTS + sys.argv[1:]))

@@ -26,22 +26,22 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
+    pos_ref,    # [B] int32, scalar-prefetched into SMEM
     q_ref,      # [1, G, dh]
     k_ref,      # [1, kb, dh]
     v_ref,      # [1, kb, dh]
-    slot_ref,   # [1, kb] int32
-    pos_ref,    # [1] int32
+    slot_ref,   # [1, 1, kb] int32
     o_ref,      # [1, G, dh]
-    m_ref,      # scratch [G]
-    l_ref,      # scratch [G]
+    m_ref,      # scratch [G, 1]
+    l_ref,      # scratch [G, 1]
     acc_ref,    # scratch [G, dh]
     *,
     scale: float,
     window: int,
     nk: int,
+    hkv: int,
 ):
     ik = pl.program_id(1)
-    g, dh = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(ik == 0)
     def _init():
@@ -52,8 +52,8 @@ def _decode_kernel(
     q = q_ref[0].astype(jnp.float32)                       # [G, dh]
     k = k_ref[0].astype(jnp.float32)                       # [kb, dh]
     v = v_ref[0].astype(jnp.float32)
-    slot = slot_ref[0]                                     # [kb]
-    cur = pos_ref[0]
+    slot = slot_ref[0]                                     # [1, kb]
+    cur = pos_ref[pl.program_id(0) // hkv]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -61,14 +61,14 @@ def _decode_kernel(
     valid = (slot >= 0) & (slot <= cur)
     if window:
         valid &= cur - slot < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
@@ -76,7 +76,7 @@ def _decode_kernel(
     @pl.when(ik == nk - 1)
     def _final():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l[:, None])[None].astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l)[None].astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -109,27 +109,32 @@ def decode_attention(
         kk = jnp.pad(kk, ((0, 0), (0, pad), (0, 0)))
         vv = jnp.pad(vv, ((0, 0), (0, pad), (0, 0)))
         sp = jnp.pad(slot_pos, ((0, 0), (0, pad)), constant_values=-1)
-    sp_ = sp.astype(jnp.int32)
+    # [B, 1, S]: the kv block is the lane axis, the unit axis is whole
+    sp_ = sp.astype(jnp.int32)[:, None, :]
     nk = (s + pad) // kb
     qg = q.reshape(b * hkv, g, dh)
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, window=window, nk=nk),
-        grid=(b * hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1, g, dh), lambda bk, ik: (bk, 0, 0)),
-            pl.BlockSpec((1, kb, dh), lambda bk, ik: (bk, ik, 0)),
-            pl.BlockSpec((1, kb, dh), lambda bk, ik: (bk, ik, 0)),
-            pl.BlockSpec((1, kb), lambda bk, ik, _hkv=hkv: (bk // _hkv, ik)),
-            pl.BlockSpec((1,), lambda bk, ik, _hkv=hkv: (bk // _hkv,)),
-        ],
-        out_specs=pl.BlockSpec((1, g, dh), lambda bk, ik: (bk, 0, 0)),
+        functools.partial(
+            _decode_kernel, scale=scale, window=window, nk=nk, hkv=hkv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * hkv, nk),
+            in_specs=[
+                pl.BlockSpec((1, g, dh), lambda bk, ik, pos: (bk, 0, 0)),
+                pl.BlockSpec((1, kb, dh), lambda bk, ik, pos: (bk, ik, 0)),
+                pl.BlockSpec((1, kb, dh), lambda bk, ik, pos: (bk, ik, 0)),
+                pl.BlockSpec(
+                    (1, 1, kb), lambda bk, ik, pos: (bk // hkv, 0, ik)),
+            ],
+            out_specs=pl.BlockSpec((1, g, dh), lambda bk, ik, pos: (bk, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, dh), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
         interpret=interpret,
-    )(qg, kk, vv, sp_, cur_pos.astype(jnp.int32))
+    )(cur_pos.astype(jnp.int32), qg, kk, vv, sp_)
     return out.reshape(b, hq, dh)

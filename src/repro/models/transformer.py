@@ -1,10 +1,10 @@
 """Decoder-only LM assembly for the dense / moe / ssm / hybrid / vlm families.
 
-Uniform stacks (dense, moe, ssm, vlm) scan over a layer-stacked parameter
+Every family scans over a layer-stacked parameter
 tree -- the HLO stays O(1) in depth, which keeps the 95-layer dry-run
 compileable -- with optional per-layer remat (ZeRO-3 FSDP all-gathers the
-layer slice inside the scan).  Non-uniform stacks (hybrid: sliding +
-global attention layers) unroll in Python.
+layer slice inside the scan).  Hybrid decode (sliding + global attention
+caches of different shapes) unrolls in Python.
 
 Public entry points (all pure, jit-able):
   train_loss(params, batch, cfg, ...)            -> scalar loss
@@ -13,7 +13,6 @@ Public entry points (all pure, jit-able):
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -87,34 +86,21 @@ def block_template(cfg: ModelConfig) -> Dict[str, Any]:
     return t
 
 
-def uses_scan(cfg: ModelConfig) -> bool:
-    """All decoder families scan over a layer-stacked parameter tree.
-
-    The hybrid (Hymba) stack is structurally uniform - every block has the
-    attention + SSM + MLP branches - only the sliding ``window`` differs
-    per layer, which rides the scan as a per-layer scalar (dynamic mask in
-    chunked_attention). This keeps the 95-layer / 32-layer full-size HLOs
-    O(1) in depth; prefill/decode for hybrid slice the stacked tree per
-    layer instead (their caches are shape-inhomogeneous).
-    """
-    return cfg.family in ("dense", "moe", "ssm", "vlm", "hybrid")
-
-
 def layer_slice(blocks, i: int):
     """Layer ``i`` of a stacked block tree."""
     return jax.tree_util.tree_map(lambda x: x[i], blocks)
 
 
 def param_template(cfg: ModelConfig) -> Dict[str, Any]:
-    blk = block_template(cfg)
-    if uses_scan(cfg):
-        blocks = jax.tree_util.tree_map(
-            lambda s: s.with_layers(cfg.num_layers),
-            blk,
-            is_leaf=lambda x: isinstance(x, ParamSpec),
-        )
-    else:
-        blocks = [block_template(cfg) for _ in range(cfg.num_layers)]
+    # Every family here stacks its blocks. The hybrid (Hymba) stack is
+    # uniform too - every block has the attention + SSM + MLP branches -
+    # only the sliding ``window`` differs per layer, which rides the scan
+    # as a per-layer scalar (dynamic mask in chunked_attention).
+    blocks = jax.tree_util.tree_map(
+        lambda s: s.with_layers(cfg.num_layers),
+        block_template(cfg),
+        is_leaf=lambda x: isinstance(x, ParamSpec),
+    )
     t: Dict[str, Any] = {
         "embed": ParamSpec((cfg.vocab_size, cfg.d_model), ("vocab", "embed"), init="embed"),
         "blocks": blocks,
@@ -272,50 +258,34 @@ def forward_hidden(
         pe = jnp.einsum("bpd,de->bpe", patches.astype(h.dtype), params["patch_proj"])
         h = jnp.concatenate([pe, h], axis=1)
 
-    # hybrid prefill collects shape-inhomogeneous caches (sliding vs
-    # global) -> slice the stacked tree per layer; everything else scans.
-    scan_ok = uses_scan(cfg) and not (cfg.family == "hybrid" and collect_cache)
-    if scan_ok:
-        windows = None
-        if cfg.family == "hybrid":
-            windows = jnp.asarray(
-                [_layer_window(cfg, i) for i in range(cfg.num_layers)],
-                jnp.int32,
-            )
+    # every layer's prefill cache (full-length k/v, SSM state) has one
+    # shape, so every family scans; ``prefill`` cuts the hybrid sliding
+    # layers' windows out of the stacked result
+    windows = None
+    if cfg.family == "hybrid":
+        windows = jnp.asarray(
+            [_layer_window(cfg, i) for i in range(cfg.num_layers)],
+            jnp.int32,
+        )
 
-        def body(carry, xs):
-            hh, aux = carry
-            bp, win = xs if windows is not None else (xs, 0)
-            hh, cache, a = block_full(
-                hh, bp, cfg, layer_window=win, prompt_lens=prompt_lens)
-            out = cache if collect_cache else None
-            return (hh, aux + a), out
+    def body(carry, xs):
+        hh, aux = carry
+        bp, win = xs if windows is not None else (xs, 0)
+        hh, cache, a = block_full(
+            hh, bp, cfg, layer_window=win, prompt_lens=prompt_lens)
+        out = cache if collect_cache else None
+        return (hh, aux + a), out
 
-        wrapped = body
-        if remat == "full":
-            wrapped = jax.checkpoint(body)
-        elif remat == "dots":
-            wrapped = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-            )
-        xs = (params["blocks"], windows) if windows is not None else params["blocks"]
-        (h, aux), caches = jax.lax.scan(wrapped, (h, jnp.float32(0.0)), xs)
-        aux = aux / cfg.num_layers
-    else:
-        caches = []
-        aux = jnp.float32(0.0)
-        stacked = uses_scan(cfg)
-        for i in range(cfg.num_layers):
-            bp = layer_slice(params["blocks"], i) if stacked else params["blocks"][i]
-            fn = functools.partial(
-                block_full, cfg=cfg, layer_window=_layer_window(cfg, i),
-                prompt_lens=prompt_lens)
-            if remat in ("full", "dots"):
-                fn = jax.checkpoint(fn)
-            h, cache, a = fn(h, bp)
-            aux = aux + a / cfg.num_layers
-            if collect_cache:
-                caches.append(cache)
+    wrapped = body
+    if remat == "full":
+        wrapped = jax.checkpoint(body)
+    elif remat == "dots":
+        wrapped = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
+        )
+    xs = (params["blocks"], windows) if windows is not None else params["blocks"]
+    (h, aux), caches = jax.lax.scan(wrapped, (h, jnp.float32(0.0)), xs)
+    aux = aux / cfg.num_layers
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     return h, caches, aux
 
@@ -455,31 +425,32 @@ def prefill(
     elif cfg.family == "ssm":
         cache["ssm"] = {"h": caches["ssm"].h, "conv_buf": caches["ssm"].conv_buf}
     elif cfg.family == "hybrid":
-        glob, slide = [], []
-        ssm_h, ssm_c = [], []
-        w = min(cfg.sliding_window, s)
-        for i, c in enumerate(caches):
-            ssm_h.append(c["ssm"].h)
-            ssm_c.append(c["ssm"].conv_buf)
-            if i in cfg.global_attn_layers:
-                glob.append((c["k"], c["v"], slot_pos))
-            else:
-                # keep trailing window, ring-ordered by absolute position % w
-                kk, vv = c["k"][:, -w:], c["v"][:, -w:]
-                pos_tail = jnp.arange(s - w, s)
-                ring_idx = jnp.argsort(pos_tail % w)
-                sp = jnp.where(
-                    pos_tail[ring_idx][None, :] < prompt_lens[:, None],
-                    pos_tail[ring_idx][None, :], -1).astype(jnp.int32)
-                slide.append((kk[:, ring_idx], vv[:, ring_idx], sp))
-        stack = lambda xs: jax.tree_util.tree_map(lambda *a: jnp.stack(a), *xs)
+        glob = [i for i in range(cfg.num_layers) if i in cfg.global_attn_layers]
+        slide = [i for i in range(cfg.num_layers) if i not in cfg.global_attn_layers]
         if glob:
-            g = stack(glob)
-            cache["attn_global"] = {"k": g[0], "v": g[1], "slot_pos": g[2]}
+            gi = jnp.asarray(glob)
+            cache["attn_global"] = {
+                "k": caches["k"][gi], "v": caches["v"][gi],
+                "slot_pos": jnp.broadcast_to(slot_pos[None], (len(glob),) + slot_pos.shape),
+            }
         if slide:
-            sl = stack(slide)
-            cache["attn_sliding"] = {"k": sl[0], "v": sl[1], "slot_pos": sl[2]}
-        cache["ssm"] = {"h": jnp.stack(ssm_h), "conv_buf": jnp.stack(ssm_c)}
+            w = min(cfg.sliding_window, s)
+            # ring slot j holds the newest prompt position p <= len-1 with
+            # p % w == j: the window that ends at each row's last real
+            # token, not at the padded width (-1 where the prompt is
+            # shorter than w)
+            last = (prompt_lens - 1).astype(jnp.int32)[:, None]
+            ring_pos = last - (last - jnp.arange(w)[None, :]) % w      # [B, w]
+            take = jnp.maximum(ring_pos, 0)[None, :, :, None, None]
+            si = jnp.asarray(slide)
+            cache["attn_sliding"] = {
+                "k": jnp.take_along_axis(caches["k"][si], take, axis=2),
+                "v": jnp.take_along_axis(caches["v"][si], take, axis=2),
+                "slot_pos": jnp.broadcast_to(
+                    jnp.where(ring_pos >= 0, ring_pos, -1).astype(jnp.int32)[None],
+                    (len(slide),) + ring_pos.shape),
+            }
+        cache["ssm"] = {"h": caches["ssm"].h, "conv_buf": caches["ssm"].conv_buf}
     return logits, cache
 
 

@@ -11,9 +11,9 @@ request never waits for the current batch to drain.
 """
 from __future__ import annotations
 
-import itertools
+import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,12 +80,29 @@ class ContinuousBatcher:
         self.slots: List[Optional[Request]] = [None] * num_slots
         self.cur_tokens = np.zeros((num_slots,), np.int32)
         self.waiting: List[Request] = []
-        self._decode = jax.jit(api.decode_step)
-        self._prefill = jax.jit(
+        # jitted on first call, or compiled ahead of time by ``compile``
+        self.decode_step = jax.jit(api.decode_step)
+        self.prefill_step = jax.jit(
             lambda p, t, pl, **kw: api.prefill(p, t, pl, **kw)
         )
         self._steps = 0
         self.all_requests: List[Request] = []
+
+    def compile(self) -> Dict[str, float]:
+        """Compile both steps ahead of time for this batcher's fixed shapes
+        (batch-1 prefill at ``cache_len``, decode over every slot) and keep
+        the executables. Returns the seconds each compile took."""
+        kw = self.extras_fn(0) if self.extras_fn else {}
+        tokens = jnp.zeros((1, self.cache_len), jnp.int32)
+        plens = jnp.ones((1,), jnp.int32)
+        t0 = time.perf_counter()  # det-lint: waive[wall-clock] reason=reports real compile time
+        self.prefill_step = self.prefill_step.lower(
+            self.params, tokens, plens, **kw).compile()
+        t1 = time.perf_counter()  # det-lint: waive[wall-clock] reason=reports real compile time
+        self.decode_step = self.decode_step.lower(
+            self.params, self.cache, jnp.asarray(self.cur_tokens)).compile()
+        t2 = time.perf_counter()  # det-lint: waive[wall-clock] reason=reports real compile time
+        return {"prefill": t1 - t0, "decode": t2 - t1}
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
@@ -113,7 +130,7 @@ class ContinuousBatcher:
             tokens = jnp.asarray([prompt + [0] * pad], jnp.int32)
             plens = jnp.asarray([len(prompt)], jnp.int32)
             kw = self.extras_fn(req.rid) if self.extras_fn else {}
-            logits, one_cache = self._prefill(self.params, tokens, plens, **kw)
+            logits, one_cache = self.prefill_step(self.params, tokens, plens, **kw)
             first = int(jnp.argmax(logits[0]))
             self.cache = insert_slot(self.cache, one_cache, slot, self.batch_axes)
             self.slots[slot] = req
@@ -138,7 +155,7 @@ class ContinuousBatcher:
         if self.active == 0:
             return []
         tokens = jnp.asarray(self.cur_tokens)
-        logits, self.cache = self._decode(self.params, self.cache, tokens)
+        logits, self.cache = self.decode_step(self.params, self.cache, tokens)
         nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         out = []
         for i, req in enumerate(self.slots):

@@ -1,11 +1,13 @@
-"""Serving: prefill-vs-decode consistency, continuous batching, and the
-trace-capture shim that calibrates the platform's batch-step model."""
+"""Serving: prefill-vs-decode consistency, continuous batching, the
+platform entry point (``repro.launch.serve``), and the trace-capture shim
+that calibrates the platform's batch-step model."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_smoke
+from repro.launch import serve as serve_lm
 from repro.models.model import build
 from repro.serving.batching import ContinuousBatcher, Request
 from repro.serving.engine import generate
@@ -19,7 +21,8 @@ from repro.serving.trace_capture import (
 RNG = jax.random.PRNGKey(0)
 
 
-@pytest.mark.parametrize("arch", ["granite-8b", "qwen2.5-32b", "mamba2-130m", "olmoe-1b-7b"])
+@pytest.mark.parametrize(
+    "arch", ["granite-8b", "qwen2.5-32b", "mamba2-130m", "olmoe-1b-7b", "hymba-1.5b"])
 def test_prefill_decode_consistency(arch):
     """Logits from decode steps must match teacher-forced prefill logits.
 
@@ -47,6 +50,25 @@ def test_prefill_decode_consistency(arch):
     # compare top-1 and logit values (bf16 accumulation tolerance)
     np.testing.assert_allclose(a, b, rtol=5e-2, atol=5e-1)
     assert (np.argmax(a, -1) == np.argmax(b, -1)).mean() >= 0.5
+
+
+@pytest.mark.parametrize("s", [10, 40])
+def test_prefill_padded_past_sliding_window(s):
+    """Prompts padded to a cache longer than the sliding window keep the
+    window that ends at their last real token, not at the padded width."""
+    cfg = get_smoke("hymba-1.5b")
+    api = build(cfg)
+    params = api.init_params(RNG)
+    width = 2 * cfg.sliding_window
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, width), 0, cfg.vocab_size)
+    prefill = jax.jit(api.prefill)
+    full_logits, _ = prefill(params, tokens, jnp.full((2,), s + 1, jnp.int32))
+    _, cache = prefill(params, tokens, jnp.full((2,), s, jnp.int32))
+    step_logits, _ = jax.jit(api.decode_step)(params, cache, tokens[:, s])
+    a = np.asarray(full_logits, np.float32)
+    b = np.asarray(step_logits, np.float32)
+    np.testing.assert_allclose(a, b, rtol=5e-2, atol=5e-2)
+    assert (np.argmax(a, -1) == np.argmax(b, -1)).all()
 
 
 def test_continuous_batcher_matches_sequential_generate():
@@ -109,3 +131,70 @@ def test_batcher_frees_slots_and_admits_waiting():
     results = batcher.run_to_completion()
     assert sorted(results) == [0, 1, 2, 3, 4]
     assert all(len(v) == 3 for v in results.values())
+
+
+SERVE_SMOKE = ["--smoke", "--arch", "hymba-1.5b", "--requests", "3",
+               "--cache-len", "48", "--min-prompt", "4", "--max-prompt", "40",
+               "--max-new", "4"]
+
+
+def test_serve_runs_every_request_through_the_platform():
+    cfg = get_smoke("hymba-1.5b")
+    api = build(cfg)
+    params = api.init_params(RNG)
+    batcher = ContinuousBatcher(api, params, num_slots=2, cache_len=48)
+    prompts = serve_lm.random_prompts(5, 4, 40, cfg.vocab_size, seed=1)
+    assert [len(p) for p in prompts] == [
+        len(p) for p in serve_lm.random_prompts(5, 4, 40, cfg.vocab_size, seed=1)]
+    served = serve_lm.serve(batcher, prompts, max_new=4)
+    assert served.ok and served.n_done == 5 and not served.failures()
+    assert set(served.compile_s) == {"prefill", "decode"}
+    # the platform's generate vertex returns what the batcher generated
+    for p, toks in zip(prompts, served.tokens):
+        padded = jnp.asarray([p.tolist() + [0] * (48 - len(p))], jnp.int32)
+        want = generate(api, params, padded, jnp.asarray([len(p)], jnp.int32), 4)
+        assert toks == np.asarray(want[0]).tolist()
+
+
+def test_serve_main_exits_nonzero_with_the_payload_exception(
+        monkeypatch, tmp_path, capsys):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+    def boom(self, max_steps=10_000):
+        raise RuntimeError("decode exploded")
+
+    monkeypatch.setattr(ContinuousBatcher, "run_to_completion", boom)
+    assert serve_lm.main(SERVE_SMOKE) == 1
+    out, err = capsys.readouterr()
+    assert "served" not in out
+    assert "0/3 requests finished" in err and "decode exploded" in err
+
+
+def test_serve_main_serves_smoke_config(monkeypatch, tmp_path, capsys):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert serve_lm.main(SERVE_SMOKE) == 0
+    out = capsys.readouterr().out
+    assert "served 3/3 requests, 12 tokens" in out
+    assert f"cache_dir={tmp_path}" in out
+
+
+def test_serve_main_refuses_full_width_without_tpu(capsys):
+    assert jax.devices()[0].platform != "tpu"
+    assert serve_lm.main(["--arch", "hymba-1.5b"]) == 2
+    assert "TPU" in capsys.readouterr().err
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert serve_lm.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # left to JAX
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = serve_lm.use_compile_cache()
+        assert path == str(serve_lm.REPO_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    ignored = (serve_lm.REPO_ROOT / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
