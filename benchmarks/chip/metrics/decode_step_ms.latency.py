@@ -1,0 +1,5 @@
+"""``decode_step_ms`` (as ``decode_step_ms.py`` reads it) in the cells judged on latency."""
+import runpy
+from pathlib import Path
+
+read = runpy.run_path(str(Path(__file__).with_name("decode_step_ms.py")))["read"]

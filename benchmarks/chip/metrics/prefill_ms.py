@@ -1,0 +1,6 @@
+"""Device time per run of the batcher's compiled prefill, from the trace."""
+
+
+def read(run):
+    s = run.trace.per_run_s("prefill") if run.trace else None
+    return None if s is None else 1e3 * s
