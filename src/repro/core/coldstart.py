@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.context import MemoryContext
 from repro.core.items import Item, SetDict
 from repro.core.registry import ComputeFunction, FunctionRegistry
@@ -137,55 +138,63 @@ def cold_start(
 
         return ctx, bd, run_modeled
 
-    t0 = time.perf_counter()
-    desc = _marshal(inputs)
-    bd.marshal = time.perf_counter() - t0
+    # the spans open outside each phase's clock reads: the breakdown does
+    # not time them
+    tracing.count("sandbox.boots")
+    with tracing.span("sandbox.marshal"):
+        t0 = time.perf_counter()
+        desc = _marshal(inputs)
+        bd.marshal = time.perf_counter() - t0
 
-    t0 = time.perf_counter()
-    code = registry.load_code(name, cached=cached)
-    bd.load = time.perf_counter() - t0
+    with tracing.span("sandbox.load"):
+        t0 = time.perf_counter()
+        code = registry.load_code(name, cached=cached)
+        bd.load = time.perf_counter() - t0
 
-    t0 = time.perf_counter()
-    ctx = MemoryContext(capacity=cf.context_bytes, tracker=tracker)
-    ctx.load_code(code)
-    for set_name, items in inputs.items():
-        ctx.write_set(set_name, items)
-    bd.transfer = time.perf_counter() - t0
+    with tracing.span("sandbox.transfer"):
+        t0 = time.perf_counter()
+        ctx = MemoryContext(capacity=cf.context_bytes, tracker=tracker)
+        ctx.load_code(code)
+        for set_name, items in inputs.items():
+            ctx.write_set(set_name, items)
+        bd.transfer = time.perf_counter() - t0
 
-    t0 = time.perf_counter()
-    runner: Callable[[], SetDict]
-    if backend == "dandelion":
-        fn = cf.fn
-        runner = lambda: fn(ctx.inputs)
-    elif backend == "snapshot":
-        blob = _AOT.snapshot_blob(cf)
-        from jax.experimental import serialize_executable
+    with tracing.span("sandbox.setup"):
+        t0 = time.perf_counter()
+        runner: Callable[[], SetDict]
+        if backend == "dandelion":
+            fn = cf.fn
+            runner = lambda: fn(ctx.inputs)
+        elif backend == "snapshot":
+            blob = _AOT.snapshot_blob(cf)
+            from jax.experimental import serialize_executable
 
-        compiled = serialize_executable.deserialize_and_load(
-            *pickle.loads(blob)
-        )
-        runner = _jax_runner(cf, compiled, ctx)
-    elif backend == "microvm":
-        if cf.jax_fn is None:
-            raise ValueError(f"{name}: microvm backend needs a jax payload")
-        import jax
+            compiled = serialize_executable.deserialize_and_load(
+                *pickle.loads(blob)
+            )
+            runner = _jax_runner(cf, compiled, ctx)
+        elif backend == "microvm":
+            if cf.jax_fn is None:
+                raise ValueError(f"{name}: microvm backend needs a jax payload")
+            import jax
 
-        # fresh closure per boot: defeats the jit cache, so every cold
-        # start really pays trace + lower + compile (the full-boot analogue)
-        payload = cf.jax_fn
-        fresh = lambda *a: payload(*a)  # noqa: E731
-        compiled = jax.jit(fresh).lower(*cf.abstract_args).compile()
-        runner = _jax_runner(cf, compiled, ctx)
-    else:
-        raise ValueError(f"unknown backend {backend!r}; known {BACKENDS}")
-    bd.execute_setup = time.perf_counter() - t0
+            # fresh closure per boot: defeats the jit cache, so every cold
+            # start really pays trace + lower + compile (the full-boot analogue)
+            payload = cf.jax_fn
+            fresh = lambda *a: payload(*a)  # noqa: E731
+            compiled = jax.jit(fresh).lower(*cf.abstract_args).compile()
+            runner = _jax_runner(cf, compiled, ctx)
+        else:
+            raise ValueError(f"unknown backend {backend!r}; known {BACKENDS}")
+        bd.execute_setup = time.perf_counter() - t0
 
     def run_and_collect() -> SetDict:
         out = runner()
-        t1 = time.perf_counter()
-        for sname, items in out.items():
-            ctx.write_set(sname, items, into="outputs")
-        bd.output = time.perf_counter() - t1
+        with tracing.span("sandbox.output"):
+            t1 = time.perf_counter()
+            for sname, items in out.items():
+                ctx.write_set(sname, items, into="outputs")
+            bd.output = time.perf_counter() - t1
         return out
 
     return ctx, bd, run_and_collect

@@ -68,6 +68,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.coldstart import ColdStartProfile, cold_start
 from repro.core.context import MemoryContext, MemoryTracker
 from repro.core.http import MIN_COMM_CPU_S, SanitizationError, http_function
@@ -174,11 +175,12 @@ class EngineSlot:
                 cached=task.cached,
                 tracker=node.tracker,
             )
-            # det-lint: waive[wall-clock] reason=real-exec path; this branch times actual payload execution, not a model
-            t0 = time.perf_counter()
-            outputs = run()
-            # det-lint: waive[wall-clock] reason=real-exec path; this branch times actual payload execution, not a model
-            exec_s = time.perf_counter() - t0
+            with tracing.span("sandbox.run", fn=task.fn_name):
+                # det-lint: waive[wall-clock] reason=real-exec path; this branch times actual payload execution, not a model
+                t0 = time.perf_counter()
+                outputs = run()
+                # det-lint: waive[wall-clock] reason=real-exec path; this branch times actual payload execution, not a model
+                exec_s = time.perf_counter() - t0
             setup_s = bd.total
 
         total = setup_s + exec_s
@@ -350,7 +352,8 @@ class EngineSlot:
                     tracker=node.tracker,
                 )
                 setup_s = bd.total
-                outputs = run()
+                with tracing.span("sandbox.run", fn=task.fn_name):
+                    outputs = run()
             served.append((task, ctx, outputs, setup_s))
             if setup_s > setup_span:
                 setup_span = setup_s

@@ -1,9 +1,13 @@
-"""Latency / memory instrumentation shared by benchmarks and tests."""
+"""Latency / memory instrumentation shared by benchmarks and tests, and
+the span-and-counter recorder of the real serving path."""
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
+import threading
+import time
 from dataclasses import dataclass, field
 from typing import Dict, IO, List, Optional, Tuple
 
@@ -54,6 +58,108 @@ class LatencyStats:
             "p99_ms": self.p99 * 1e3,
             "rel_var_pct": self.relative_variance,
         }
+
+
+# ===========================================================================
+# Span-and-counter recorder for the real serving path (off by default)
+# ===========================================================================
+@dataclass
+class SpanRecord:
+    """One span: host clock (``perf_counter`` seconds; ``end`` is nan while
+    it is open), the index in the records of the span open on the same
+    thread when it started (-1 for none), and the call site's attributes."""
+
+    name: str
+    start: float
+    end: float
+    parent: int
+    attrs: Dict[str, object]
+
+
+class _NoSpan:
+    """What ``span`` returns while the recorder is off: one shared object."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+_NO_SPAN = _NoSpan()
+_on = False
+_annotate = None                 # jax.profiler.TraceAnnotation, bound by enable()
+_records: List[SpanRecord] = []
+_counters: Dict[str, int] = {}
+_lock = threading.Lock()
+_open = threading.local()        # .top: index of this thread's innermost open span
+
+
+class _Span:
+    __slots__ = ("record", "annotation")
+
+    def __init__(self, name: str, attrs: Dict[str, object]):
+        self.record = SpanRecord(name, math.nan, math.nan, -1, attrs)
+
+    def __enter__(self):
+        rec = self.record
+        rec.parent = getattr(_open, "top", -1)
+        self.annotation = _annotate(rec.name)
+        self.annotation.__enter__()
+        with _lock:
+            _open.top = len(_records)
+            _records.append(rec)
+        rec.start = time.perf_counter()  # det-lint: waive[wall-clock] reason=real serving path spans, never read by the simulator
+        return self
+
+    def __exit__(self, *exc):
+        rec = self.record
+        rec.end = time.perf_counter()  # det-lint: waive[wall-clock] reason=real serving path spans, never read by the simulator
+        self.annotation.__exit__(*exc)
+        _open.top = rec.parent
+        return None
+
+
+def span(name: str, **attrs):
+    """Context manager recording ``name`` while the recorder is on: a
+    ``jax.profiler.TraceAnnotation`` of that name, on the device trace's
+    clock, and a ``SpanRecord``. Off, it costs one flag test."""
+    if not _on:
+        return _NO_SPAN
+    return _Span(name, attrs)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the counter ``name`` while the recorder is on."""
+    if _on:
+        _counters[name] = _counters.get(name, 0) + n
+
+
+def enable() -> None:
+    """Turn the recorder on; this imports jax for its ``TraceAnnotation``."""
+    global _on, _annotate
+    from jax.profiler import TraceAnnotation
+
+    _annotate = TraceAnnotation
+    _on = True
+
+
+def disable() -> None:
+    global _on
+    _on = False
+
+
+def take() -> Tuple[List[SpanRecord], Dict[str, int]]:
+    """The records and counters so far, which are then cleared. Call it
+    with no span open: a parent index points into the list it returned."""
+    global _records, _counters
+    with _lock:
+        out = _records, _counters
+        _records, _counters = [], {}
+        _open.top = -1
+    return out
 
 
 class StreamingPercentile:

@@ -12,9 +12,8 @@ compositions do not:
     weight-read term amortizes over the batch, so elastic scale-out
     trades batch efficiency against queueing (the paper's fig-8
     multiplexing story at cluster scale). The terms come from
-    ``repro.launch.hlo_analysis`` cost models (or the trace-capture
-    calibration in ``repro.serving.trace_capture``); this class keeps
-    only plain floats so core stays below the launch/serving layers.
+    ``repro.launch.hlo_analysis`` cost models; this class keeps only
+    plain floats so core stays below the launch/serving layers.
 
 ``WeightStore``
     Per-node model-weight residency: the multi-GB weight term that

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.models.common import is_spec
 from repro.models.model import ModelApi
 
@@ -80,11 +81,18 @@ class ContinuousBatcher:
         self.slots: List[Optional[Request]] = [None] * num_slots
         self.cur_tokens = np.zeros((num_slots,), np.int32)
         self.waiting: List[Request] = []
-        # jitted on first call, or compiled ahead of time by ``compile``
-        self.decode_step = jax.jit(api.decode_step)
-        self.prefill_step = jax.jit(
-            lambda p, t, pl, **kw: api.prefill(p, t, pl, **kw)
-        )
+        # jitted on first call, or compiled ahead of time by ``compile``;
+        # named functions, so the programs are ``jit_prefill`` and
+        # ``jit_decode_step`` in a profiler's trace
+
+        def prefill(p, t, pl, **kw):
+            return api.prefill(p, t, pl, **kw)
+
+        def decode_step(p, c, t):
+            return api.decode_step(p, c, t)
+
+        self.prefill_step = jax.jit(prefill)
+        self.decode_step = jax.jit(decode_step)
         self._steps = 0
         self.all_requests: List[Request] = []
 
@@ -127,16 +135,24 @@ class ContinuousBatcher:
             req = self.waiting.pop(0)
             prompt = req.prompt[: self.cache_len]
             pad = self.cache_len - len(prompt)
-            tokens = jnp.asarray([prompt + [0] * pad], jnp.int32)
-            plens = jnp.asarray([len(prompt)], jnp.int32)
-            kw = self.extras_fn(req.rid) if self.extras_fn else {}
-            logits, one_cache = self.prefill_step(self.params, tokens, plens, **kw)
-            first = int(jnp.argmax(logits[0]))
-            self.cache = insert_slot(self.cache, one_cache, slot, self.batch_axes)
-            self.slots[slot] = req
-            req.generated.append(first)
-            self.cur_tokens[slot] = first
-            self._maybe_finish(slot)
+            with tracing.span("batcher.admit", rid=req.rid, slot=slot,
+                              prompt_len=len(prompt)):
+                with tracing.span("batcher.prefill"):
+                    tokens = jnp.asarray([prompt + [0] * pad], jnp.int32)
+                    plens = jnp.asarray([len(prompt)], jnp.int32)
+                    kw = self.extras_fn(req.rid) if self.extras_fn else {}
+                    logits, one_cache = self.prefill_step(self.params, tokens, plens, **kw)
+                with tracing.span("batcher.sync"):
+                    first = int(jnp.argmax(logits[0]))
+                with tracing.span("batcher.insert_slot"):
+                    self.cache = insert_slot(self.cache, one_cache, slot, self.batch_axes)
+                tracing.count("batcher.prefills")
+                tracing.count("batcher.prefill_tokens", len(prompt))
+                tracing.count("batcher.prefill_padded_tokens", self.cache_len)
+                self.slots[slot] = req
+                req.generated.append(first)
+                self.cur_tokens[slot] = first
+                self._maybe_finish(slot)
 
     def _maybe_finish(self, slot: int):
         req = self.slots[slot]
@@ -152,22 +168,29 @@ class ContinuousBatcher:
     def step(self) -> List[Tuple[int, int]]:
         """Admit waiting requests, run one decode step, emit (rid, token)."""
         self._admit()
-        if self.active == 0:
+        rids = [r.rid for r in self.slots if r is not None]
+        if not rids:
             return []
-        tokens = jnp.asarray(self.cur_tokens)
-        logits, self.cache = self.decode_step(self.params, self.cache, tokens)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        out = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(nxt[i])
-            req.generated.append(tok)
-            self.cur_tokens[i] = tok
-            out.append((req.rid, tok))
-            self._maybe_finish(i)
-        self._steps += 1
-        return out
+        with tracing.span("batcher.step", rids=rids):
+            with tracing.span("batcher.decode"):
+                tokens = jnp.asarray(self.cur_tokens)
+                logits, self.cache = self.decode_step(self.params, self.cache, tokens)
+            # the sync's end is when each listed request's token reached the host
+            with tracing.span("batcher.sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            tracing.count("batcher.decode_steps")
+            tracing.count("batcher.decode_slot_steps", len(rids))
+            out = []
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tok = int(nxt[i])
+                req.generated.append(tok)
+                self.cur_tokens[i] = tok
+                out.append((req.rid, tok))
+                self._maybe_finish(i)
+            self._steps += 1
+            return out
 
     def run_to_completion(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         for _ in range(max_steps):

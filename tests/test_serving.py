@@ -1,22 +1,16 @@
-"""Serving: prefill-vs-decode consistency, continuous batching, the
-platform entry point (``repro.launch.serve``), and the trace-capture shim
-that calibrates the platform's batch-step model."""
+"""Serving: prefill-vs-decode consistency, continuous batching and its
+spans and counters, and the platform entry point (``repro.launch.serve``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_smoke
+from repro.core import tracing
 from repro.launch import serve as serve_lm
 from repro.models.model import build
 from repro.serving.batching import ContinuousBatcher, Request
 from repro.serving.engine import generate
-from repro.serving.trace_capture import (
-    calibrated_batch_model,
-    calibration_residuals,
-    capture_step_timings,
-    fit_affine,
-)
 
 RNG = jax.random.PRNGKey(0)
 
@@ -95,32 +89,6 @@ def test_continuous_batcher_matches_sequential_generate():
         assert results[rid] == want, f"req {rid}: {results[rid]} != {want}"
 
 
-def test_trace_capture_calibrates_batch_model():
-    """Real jitted step timings fit the platform's BatchStepModel shape:
-    the calibrated model reproduces the measured affine decode curve."""
-    cfg = get_smoke("mamba2-130m")
-    api = build(cfg)
-    params = api.init_params(RNG)
-    timings = capture_step_timings(
-        api, params, batches=(1, 2), cache_len=16, prompt_len=4, samples=2,
-    )
-    assert [t.batch for t in timings] == [1, 2]
-    assert all(t.prefill_s > 0 and t.decode_s > 0 for t in timings)
-    fixed, per_seq = fit_affine(timings)
-    model = calibrated_batch_model(timings)
-    assert model.step_s(1) == pytest.approx(fixed + per_seq)
-    assert model.step_s(2) == pytest.approx(fixed + 2 * per_seq)
-    # batching a calibrated model never beats per-sequence linearity
-    assert model.step_s(4) <= 4 * model.step_s(1) + 1e-12
-    # the residual report scores the fit through the vectorized pricing
-    # path; a 2-point affine fit of 2 points is (near) exact unless the
-    # lstsq clamp to nonnegative coefficients kicked in
-    res = calibration_residuals(timings, model)
-    assert [b for b, _ in res] == [1, 2]
-    if fixed > 0 and per_seq > 0:
-        assert all(abs(r) < 1e-6 for _, r in res)
-
-
 def test_batcher_frees_slots_and_admits_waiting():
     cfg = get_smoke("mamba2-130m")
     api = build(cfg)
@@ -131,6 +99,74 @@ def test_batcher_frees_slots_and_admits_waiting():
     results = batcher.run_to_completion()
     assert sorted(results) == [0, 1, 2, 3, 4]
     assert all(len(v) == 3 for v in results.values())
+
+
+def _serve_batch(api, params, prompts, max_new, traced):
+    """Serve ``prompts`` through a 2-slot batcher; with ``traced`` the
+    recorder is on, and its records and counters are returned too."""
+    batcher = ContinuousBatcher(api, params, num_slots=2, cache_len=16)
+    for rid, p in enumerate(prompts):
+        batcher.submit(Request(rid, p, max_new_tokens=max_new[rid]))
+    if traced:
+        tracing.enable()
+    try:
+        results = batcher.run_to_completion()
+    finally:
+        tracing.disable()
+    return results, tracing.take()
+
+
+def test_batcher_counters_equal_what_it_served():
+    cfg = get_smoke("mamba2-130m")
+    api = build(cfg)
+    params = api.init_params(RNG)
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6], [5, 3], [5, 8, 9, 7, 9, 3, 2]]
+    max_new = [4, 2, 5, 3]
+    plain, _ = _serve_batch(api, params, prompts, max_new, traced=False)
+    results, (records, counters) = _serve_batch(api, params, prompts, max_new, traced=True)
+    assert results == plain
+    steps = [r for r in records if r.name == "batcher.step"]
+    assert counters == {
+        "batcher.prefills": 4,
+        "batcher.prefill_tokens": sum(map(len, prompts)),
+        "batcher.prefill_padded_tokens": 4 * 16,
+        "batcher.decode_steps": len(steps),
+        "batcher.decode_slot_steps": sum(len(r.attrs["rids"]) for r in steps),
+    }
+    # every request's first token comes from its prefill, each later one
+    # from a step that listed it
+    for rid, p in enumerate(prompts):
+        assert sum(rid in r.attrs["rids"] for r in steps) == max_new[rid] - 1
+
+
+def test_batcher_spans_nest_as_admit_and_step():
+    cfg = get_smoke("mamba2-130m")
+    api = build(cfg)
+    params = api.init_params(RNG)
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6], [5, 3]]
+    _, (records, _) = _serve_batch(api, params, prompts, [3, 2, 2], traced=True)
+    children = {}
+    for r in records:
+        children.setdefault(r.parent, []).append(r.name)
+    admits = [i for i, r in enumerate(records) if r.name == "batcher.admit"]
+    steps = [i for i, r in enumerate(records) if r.name == "batcher.step"]
+    assert [records[i].attrs for i in admits] == [
+        {"rid": 0, "slot": 0, "prompt_len": 3}, {"rid": 1, "slot": 1, "prompt_len": 5},
+        {"rid": 2, "slot": 1, "prompt_len": 2}]   # rid 1 finished first
+    assert all(records[i].parent == -1 for i in admits + steps)
+    assert all(children[i] == ["batcher.prefill", "batcher.sync", "batcher.insert_slot"]
+               for i in admits)
+    assert all(children[i] == ["batcher.decode", "batcher.sync"] for i in steps)
+
+
+def test_batcher_programs_are_named_prefill_and_decode_step():
+    cfg = get_smoke("mamba2-130m")
+    api = build(cfg)
+    batcher = ContinuousBatcher(api, api.init_params(RNG), num_slots=2, cache_len=16)
+    batcher.compile()
+    names = [step.runtime_executable().hlo_modules()[0].name
+             for step in (batcher.prefill_step, batcher.decode_step)]
+    assert names == ["jit_prefill", "jit_decode_step"]
 
 
 SERVE_SMOKE = ["--smoke", "--arch", "hymba-1.5b", "--requests", "3",
