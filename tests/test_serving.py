@@ -9,7 +9,7 @@ from repro.configs import ARCH_IDS, get_smoke
 from repro.core import tracing
 from repro.launch import serve as serve_lm
 from repro.models.model import build
-from repro.serving.batching import ContinuousBatcher, Request
+from repro.serving.batching import ContinuousBatcher, Request, _batch_axis_tree, insert_slot
 from repro.serving.engine import generate
 
 RNG = jax.random.PRNGKey(0)
@@ -101,12 +101,62 @@ def test_batcher_frees_slots_and_admits_waiting():
     assert all(len(v) == 3 for v in results.values())
 
 
-def _serve_batch(api, params, prompts, max_new, traced):
+def _greedy_alone(api, params, req, num_slots, cache_len):
+    """``req`` served alone by a plain synchronous greedy loop over the
+    model's prefill and decode step: a batch-1 prefill put into slot 0 of a
+    ``num_slots``-slot cache, then one decode step at a time, each token read
+    on the host before the next step runs."""
+    p = req.prompt
+    logits, one = jax.jit(api.prefill)(
+        params, jnp.asarray([p + [0] * (cache_len - len(p))], jnp.int32),
+        jnp.asarray([len(p)], jnp.int32))
+    cache = insert_slot(api.init_cache(num_slots, cache_len), one, 0,
+                        _batch_axis_tree(api, num_slots, cache_len))
+    out = [int(jnp.argmax(logits[0]))]
+    while len(out) < req.max_new_tokens and out[-1] != req.eos_id:
+        tokens = np.zeros((num_slots,), np.int32)
+        tokens[0] = out[-1]
+        logits, cache = jax.jit(api.decode_step)(params, cache, jnp.asarray(tokens))
+        out.append(int(jnp.argmax(logits[0])))
+    return out
+
+
+@pytest.mark.parametrize("eos", [False, True], ids=["budget", "eos"])
+@pytest.mark.parametrize("arch", ["granite-8b", "hymba-1.5b", "mamba2-130m"])
+def test_overlapped_batcher_serves_the_synchronous_greedy_tokens(arch, eos):
+    """The batcher runs one step ahead of the host; what it serves is still
+    what a synchronous greedy loop serves. Five requests share two slots, so
+    slots are re-admitted while the step that freed them is in flight; one
+    request asks for a single token. With ``eos``, request 0 stops at the
+    first of its third token, and request 2 at its first token, an EOS that
+    the batcher sees only after the next step is queued."""
+    cfg = get_smoke(arch)
+    api = build(cfg)
+    params = api.init_params(RNG)
+    slots, cache_len = 2, 24
+    prompts = [[5, 9, 2, 7], [1, 2, 3], [11, 4, 8, 15, 16], [3, 3], [7, 1, 8, 2, 8, 1]]
+    reqs = [Request(rid, p, max_new_tokens=n)
+            for rid, (p, n) in enumerate(zip(prompts, [4, 1, 6, 2, 5]))]
+    if eos:
+        reqs[0].eos_id = _greedy_alone(api, params, reqs[0], slots, cache_len)[2]
+        reqs[2].eos_id = _greedy_alone(api, params, reqs[2], slots, cache_len)[0]
+    want = {r.rid: _greedy_alone(api, params, r, slots, cache_len) for r in reqs}
+    if eos:
+        assert len(want[0]) <= 3 and want[2] == [reqs[2].eos_id]
+    batcher = ContinuousBatcher(api, params, num_slots=slots, cache_len=cache_len)
+    for r in reqs:
+        batcher.submit(r)
+    assert batcher.run_to_completion() == want
+    assert all(r.done for r in reqs)
+
+
+def _serve_batch(api, params, prompts, max_new, traced, eos=None):
     """Serve ``prompts`` through a 2-slot batcher; with ``traced`` the
     recorder is on, and its records and counters are returned too."""
     batcher = ContinuousBatcher(api, params, num_slots=2, cache_len=16)
     for rid, p in enumerate(prompts):
-        batcher.submit(Request(rid, p, max_new_tokens=max_new[rid]))
+        batcher.submit(Request(rid, p, max_new_tokens=max_new[rid],
+                               eos_id=(eos or {}).get(rid, -1)))
     if traced:
         tracing.enable()
     try:
@@ -126,12 +176,16 @@ def test_batcher_counters_equal_what_it_served():
     results, (records, counters) = _serve_batch(api, params, prompts, max_new, traced=True)
     assert results == plain
     steps = [r for r in records if r.name == "batcher.step"]
+    # one copy per prefill and per step (nine); each but the last step's is
+    # made behind a later program, the last once nothing is left to queue
     assert counters == {
         "batcher.prefills": 4,
         "batcher.prefill_tokens": sum(map(len, prompts)),
         "batcher.prefill_padded_tokens": 4 * 16,
         "batcher.decode_steps": len(steps),
         "batcher.decode_slot_steps": sum(len(r.attrs["rids"]) for r in steps),
+        "batcher.syncs": 4 + 5,
+        "batcher.syncs_behind": 8,
     }
     # every request's first token comes from its prefill, each later one
     # from a step that listed it
@@ -154,9 +208,43 @@ def test_batcher_spans_nest_as_admit_and_step():
         {"rid": 0, "slot": 0, "prompt_len": 3}, {"rid": 1, "slot": 1, "prompt_len": 5},
         {"rid": 2, "slot": 1, "prompt_len": 2}]   # rid 1 finished first
     assert all(records[i].parent == -1 for i in admits + steps)
-    assert all(children[i] == ["batcher.prefill", "batcher.sync", "batcher.insert_slot"]
-               for i in admits)
-    assert all(children[i] == ["batcher.decode", "batcher.sync"] for i in steps)
+    # the first tokens stay on the device: admission holds no copy
+    assert all(children[i] == ["batcher.prefill", "batcher.insert_slot"] for i in admits)
+    assert all(children[i] == ["batcher.decode", "batcher.sync", "batcher.sync"]
+               for i in steps)
+    assert children[-1] == ["batcher.admit", "batcher.admit", "batcher.step",
+                            "batcher.admit", "batcher.step", "batcher.sync"]
+    # each step copies what was queued before it: the two first tokens, then
+    # step 1 and rid 2's first token; step 2's tokens come once nothing is
+    # left to queue, so that copy alone is not behind a later program
+    syncs = [(r.attrs["rids"], r.attrs["behind"]) for r in records if r.name == "batcher.sync"]
+    assert syncs == [([0], True), ([1], True), ([0, 1], True), ([2], True), ([0, 2], False)]
+
+
+def test_batcher_counts_copies_behind_and_discarded_slot_steps():
+    """Two slots; budgets 4, 2, 5, 3; rid 2's first token is its EOS.
+    Hand count, a copy per line (B: behind a later program):
+      step 1 (rids 0, 1; rid 1 done by budget): copy P0 B, P1 B
+      step 2 (rids 0, 2): copy step 1 B, P2 B - rid 2's EOS, its slot is freed
+      step 3 (rids 0, 3; rid 0 done): copy step 2 B - rid 2's token discarded; P3 B
+      step 4 (rid 3; done): copy step 3 B
+      nothing to queue: copy step 4, not behind."""
+    cfg = get_smoke("mamba2-130m")
+    api = build(cfg)
+    params = api.init_params(RNG)
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6], [5, 3], [5, 8, 9, 7, 9, 3, 2]]
+    max_new = [4, 2, 5, 3]
+    plain, _ = _serve_batch(api, params, prompts, max_new, traced=False)
+    eos = {2: plain[2][0]}
+    results, (records, counters) = _serve_batch(api, params, prompts, max_new, traced=True,
+                                                eos=eos)
+    assert results == {**plain, 2: plain[2][:1]}
+    assert [r.attrs["rids"] for r in records if r.name == "batcher.step"] == [
+        [0, 1], [0, 2], [0, 3], [3]]
+    assert counters["batcher.syncs"] == 8
+    assert counters["batcher.syncs_behind"] == 7
+    assert counters["batcher.discarded_slot_steps"] == 1
+    assert counters["batcher.decode_slot_steps"] == 7
 
 
 def test_batcher_programs_are_named_prefill_and_decode_step():
