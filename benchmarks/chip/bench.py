@@ -19,13 +19,16 @@ One process, which holds the chip, does in order:
    open loop times each request from when it was due, a closed loop keeps
    a fixed number of clients waiting on replies; one thread sends and
    serves, as the serving path takes one request at a time;
-8. compares what was served with the plain reference (``check.py``);
+8. compares what was served with the configuration's plain reference
+   (``check.py``; the reference by ``arch.py``);
 9. prints the result as the last line of stdout.
 
 ``--trace 1`` runs the profiler over the window and reports the cell's
 per-layer metrics (``metrics/<name>.py``) instead of its end-to-end ones.
 Everything a cell needs is found by name, so a cell is added with data
-files and a ``workloads`` entry in ``BENCHMARK.json``.
+files and a ``workloads`` entry in ``BENCHMARK.json``, and an architecture
+with its reference and counts as new files that its configuration names
+(``arch.py``).
 """
 from __future__ import annotations
 
@@ -37,7 +40,6 @@ import argparse
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -49,6 +51,8 @@ from typing import Callable, Dict, List, Optional
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 sys.path[:0] = [str(ROOT / "src"), str(HERE)]
+
+from arch import load_module  # noqa: E402
 
 # after the window closes, requests due in it are still waited for this long
 GRACE_S = 60.0
@@ -100,14 +104,6 @@ def load_cell(root: Path, workload: str) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m else m["moves"] in names)]
     return Cell(workload, int(w["chips"]), config, traffic, e2e, per_layer, bench_dir)
-
-
-def load_module(path: Path):
-    name = f"chipbench_{path.parent.name}_{path.stem}".replace(".", "_")
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ---------------------------------------------------------------------------
@@ -449,7 +445,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool, *,
     longest = mix["prompt"]["max"] + mix["output"]["max"]
     ref_times: Dict[str, float] = {}
     shape = (chk["sample_requests"], longest, chk["sample_requests"] * mix["output"]["max"])
-    read = check.served_gap(model, seed, sample, serving["cache_len"], shape, control, ref_times) \
+    read = check.served_gap(cell, seed, sample, shape, control, ref_times) \
         if sample else {"served_gap": float("inf")}
     reference_s = time.perf_counter() - t
     checks = {"served_gap": {"value": read["served_gap"], "limit": chk["served_gap_limit"]},

@@ -2,7 +2,8 @@
 
 Once the window has closed, a sample of the requests it finished, drawn
 from the seed with the longest among them, is run through the plain
-float32 reference (``reference/model.py``), each prompt followed by the
+float32 reference (the configuration's, by ``arch.py``: ``reference/model.py``
+unless it names another), each prompt followed by the
 tokens that were served for it. For every served token the reference
 gives the logits at the position that produced it; the number compared is
 the widest gap by which a served token's logit lies below the reference's
@@ -17,7 +18,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from reference import model as reference
+import arch
 
 
 def draw_sample(done: Sequence, seed: int, size: int) -> List:
@@ -50,16 +51,17 @@ def gaps(logits: np.ndarray, chosen: np.ndarray) -> np.ndarray:
     return logits.max(-1) - logits[np.arange(len(chosen)), chosen]
 
 
-def served_gap(model: Dict, seed: int, sample: Sequence, cache_len: int,
-               shape: Tuple[int, int, int], control: str = "",
-               timings: Dict[str, float] = None) -> Dict[str, float]:
-    """``served_gap`` of the sample; with ``control`` (a lower precision),
-    also ``control_gap``: the same number for the reference computed in
-    that precision put in the program's place (its first choice at each
-    position). The reference runs at a fixed ``shape`` (sequences,
-    positions, served tokens), so that its programs compile once for every
-    run of a cell."""
-    seqs, rows, served = sequences(sample, cache_len)
+def served_gap(cell, seed: int, sample: Sequence, shape: Tuple[int, int, int],
+               control: str = "", timings: Dict[str, float] = None) -> Dict[str, float]:
+    """``served_gap`` of the sample served in ``cell`` (a ``bench.Cell``);
+    with ``control`` (a lower precision), also ``control_gap``: the same
+    number for the reference computed in that precision put in the
+    program's place (its first choice at each position). The reference
+    runs at a fixed ``shape`` (sequences, positions, served tokens), so
+    that its programs compile once for every run of a cell."""
+    reference = arch.reference(cell)
+    model = cell.model
+    seqs, rows, served = sequences(sample, cell.config["serving"]["cache_len"])
     ref = reference.logits_at(model, seed, seqs, rows, "float32", shape, timings)
     out = {"served_gap": float(gaps(ref, served).max())}
     if control:

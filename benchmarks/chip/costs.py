@@ -1,4 +1,11 @@
-"""Operations and bytes of the served model's steps, from its sizes alone.
+"""Operations and bytes of the served model's steps, from its sizes alone:
+the default counts of ``arch.py`` for the dense and the hybrid families.
+
+A costs module offers ``param_count(m)``, ``param_bytes(m)``,
+``prefill_flops(m, prompt_len)``, ``decode_flops(m, positions)``,
+``decode_bytes(m, positions)`` and ``roofline_s(flops, nbytes, peak)``,
+``m`` being the configuration's ``model`` dict; another architecture's
+module may import ``roofline_s`` and ``DTYPE_BYTES`` from here.
 
 Counted from the configuration file's numbers, not from the program:
   * a multiply-add is 2 operations;

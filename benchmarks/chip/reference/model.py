@@ -1,4 +1,7 @@
-"""A plain forward pass of the dense and the hybrid (Hymba) decoder.
+"""A plain forward pass of the dense and the hybrid (Hymba) decoder: the
+default reference of ``arch.py``. A reference module offers
+``logits_at(m, seed, seqs, rows, numerics, shape, timings)`` and
+``block(h, key, l, window, m, numerics)``, one layer, as below.
 
 Written from the published descriptions in straightforward ``jax.numpy``,
 one layer at a time, at full length with no cache, no padding tricks and
@@ -185,7 +188,9 @@ def _layer_weights(key, l, m):
             for name, (shape, init) in layer_leaves(m).items()}
 
 
-def _block(h, key, l, window, m, numerics):
+def block(h, key, l, window, m, numerics):
+    """Layer ``l`` over ``h`` [sequences, positions, d_model] in float32;
+    ``window`` is the layer's attention window, 0 for none."""
     w = _layer_weights(key, l, m)
     eps = m["norm_eps"]
     x = _rms(h, w["norm1"], eps)
@@ -247,10 +252,10 @@ def logits_at(m: Dict, seed: int, seqs: Sequence[np.ndarray], rows: Sequence[np.
 
     h = jax.jit(functools.partial(_embed, m=m))(key, jnp.asarray(tokens))
     l0 = jnp.int32(0)
-    block = jax.jit(functools.partial(_block, m=m, numerics=numerics)).lower(h, key, l0, l0).compile()
+    layer = jax.jit(functools.partial(block, m=m, numerics=numerics)).lower(h, key, l0, l0).compile()
     t1 = time.perf_counter()
     for l in range(m["num_layers"]):
-        h = block(h, key, jnp.int32(l), jnp.int32(_window(m, l)))
+        h = layer(h, key, jnp.int32(l), jnp.int32(_window(m, l)))
     h.block_until_ready()
     t2 = time.perf_counter()
     out = np.asarray(jax.jit(functools.partial(_logits, m=m, numerics=numerics))(

@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Compile a cell's programs for a described, unattached TPU v5e and print
 their memory, with no chip: the batcher's prefill and decode steps, the
-one-call weight maker, and one layer of the plain reference.
+one-call weight maker, and one layer (``block``) of the configuration's
+plain reference (``arch.py``).
 
     JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse_tpu_compile.py granite-8b-18l
 
@@ -28,8 +29,8 @@ def main(config_name: str) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    import arch
     import weights
-    from reference import model as reference
     from repro.config import ModelConfig
     from repro.models.common import is_spec
     from repro.models.model import build
@@ -55,10 +56,11 @@ def main(config_name: str) -> int:
             jax.ShapeDtypeStruct((serving["slots"],), jnp.int32)))),
         "weights": (jax.jit(weights.params_fn(api.param_template, cfg.dtype, is_leaf=is_spec)),
                     (key,)),
-        "reference_layer": (jax.jit(functools.partial(reference._block, m=m, numerics="float32")),
-                            (on_chip(jax.ShapeDtypeStruct((12, s_ref, m["d_model"]), jnp.float32)),
-                             key, on_chip(jax.ShapeDtypeStruct((), jnp.int32)),
-                             on_chip(jax.ShapeDtypeStruct((), jnp.int32)))),
+        "reference_layer": (
+            jax.jit(functools.partial(arch.reference(conf).block, m=m, numerics="float32")),
+            (on_chip(jax.ShapeDtypeStruct((12, s_ref, m["d_model"]), jnp.float32)),
+             key, on_chip(jax.ShapeDtypeStruct((), jnp.int32)),
+             on_chip(jax.ShapeDtypeStruct((), jnp.int32)))),
     }
     for name, (fn, args) in progs.items():
         t = time.perf_counter()

@@ -18,12 +18,13 @@ sys.path[:0] = [str(ROOT / "src"), str(HERE)]
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-import costs  # noqa: E402
+import arch  # noqa: E402
 import latency_stats  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIGS = {c["name"]: json.loads((ROOT / c["file"]).read_text()) for c in BENCH["configs"]}
 CHAT = json.loads((HERE / "traffic" / "chat.json").read_text())
 BATCH = json.loads((HERE / "traffic" / "batch-gen.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -86,18 +87,65 @@ def test_tokens_prorated_to_the_window():
     assert latency_stats.prorated([(5.0, 5.0, 3)], 0.0, 10.0) == 3
 
 
-@pytest.mark.parametrize("config", ["hymba-1.5b", "granite-8b-18l"])
+@pytest.mark.parametrize("config", list(CONFIGS))
 def test_costs_count_the_programs_parameters(config):
     from repro.config import ModelConfig
     from repro.models.model import build
 
-    m = json.loads((HERE / "configs" / f"{config}.json").read_text())["model"]
+    costs, m = arch.costs(CONFIGS[config]), CONFIGS[config]["model"]
     api = build(ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in m.items()}))
     assert costs.param_count(m) == api.param_count()
     assert costs.param_bytes(m) == api.param_bytes()
     # a decode step moves at least its weights, and more with more context
     assert costs.decode_bytes(m, [10]) < costs.decode_bytes(m, [1000]) < costs.decode_bytes(m, [10, 1000])
-    assert costs.decode_flops(m, [5]) > 2 * costs.layer_matmul_params(m) * m["num_layers"]
+    # a one-token prompt is the work of one decode step at position 0
+    assert costs.prefill_flops(m, 1) == costs.decode_flops(m, [0]) < costs.decode_flops(m, [1000])
+    assert costs.decode_flops(m, [5, 5]) == 2 * costs.decode_flops(m, [5])
+
+
+# (param_count, param_bytes) and, at each set of positions, (prefill_flops
+# of a prompt as long as the last position + 1, decode_flops, decode_bytes),
+# as the counts read before a configuration could name its own
+POSITIONS = ([0], [10, 1000], [5, 517, 1023, 1024, 2047], list(range(0, 2048, 97)))
+COUNTED = {
+    "hymba-1.5b": ((1589773120, 3179555840), [
+        (3187920000.0, 3187920000.0, 3193986048.0),
+        (3191207120000.0, 6582688000.0, 3249785856.0),
+        (6651124944000.0, 16694716800.0, 3402730240.0),
+        (6617978928000.0, 73643187200.0, 4198809856.0)]),
+    "granite-8b-18l": ((4127457280, 8254914560), [
+        (8254685184.0, 8254685184.0, 8255062016.0),
+        (8007890141184.0, 16807231488.0, 8329674752.0),
+        (16699537489920.0, 42634739712.0, 8595980288.0),
+        (16614993592320.0, 188211167232.0, 9910181888.0)]),
+}
+
+
+@pytest.mark.parametrize("config", list(COUNTED))
+def test_the_counts_reached_by_name_are_the_ones_read_before(config):
+    costs, m = arch.costs(CONFIGS[config]), CONFIGS[config]["model"]
+    params, per_positions = COUNTED[config]
+    assert (costs.param_count(m), costs.param_bytes(m)) == params
+    for pos, counted in zip(POSITIONS, per_positions):
+        assert (costs.prefill_flops(m, pos[-1] + 1), costs.decode_flops(m, pos),
+                costs.decode_bytes(m, pos)) == counted, pos
+
+
+@pytest.mark.parametrize("config", list(COUNTED))
+def test_a_configuration_without_arch_takes_the_default_modules(config):
+    conf = CONFIGS[config]
+    assert "arch" not in conf and not set(conf["model"]) & {"arch", "smoke"}
+    assert arch.path("reference", conf) == HERE / "reference" / "model.py"
+    assert arch.path("costs", conf) == HERE / "costs.py"
+    assert callable(arch.reference(conf).logits_at) and callable(arch.costs(conf).roofline_s)
+    with pytest.raises(ValueError, match="refernce"):
+        arch.path("costs", dict(conf, arch={"refernce": "reference/other.py"}))
+    # a named module that lies outside the benchmark's directory is refused
+    for outside in ("../../src/repro/config/model.py", str(HERE.parent / "chip_other" / "costs.py"),
+                    "reference/../../chip_other/costs.py"):
+        with pytest.raises(ValueError, match="outside"):
+            arch.path("costs", dict(conf, arch={"costs": outside}))
+    assert arch.path("costs", dict(conf, arch={"costs": "reference/../costs.py"})) == HERE / "costs.py"
 
 
 class _Spans:
@@ -206,7 +254,7 @@ def test_manifest_names_units_and_files():
     assert len(json.dumps(b)) < 64 * 1024
 
 
-@pytest.mark.parametrize("config", ["hymba-1.5b", "granite-8b-18l"])
+@pytest.mark.parametrize("config", list(CONFIGS))
 def test_every_request_of_the_traffic_fits_the_cache(config):
     import bench
 

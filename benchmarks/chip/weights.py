@@ -6,6 +6,12 @@ either handing the other anything: the harness maps the program's
 parameter template through ``draw``, and the reference calls ``draw`` for
 the leaves it knows by name, one layer at a time.
 
+A stacked leaf whose axes after ``layers`` begin with ``experts`` is drawn
+one expert at a time (``draw_expert``): expert ``i`` from the layer's key
+folded with ``i``, its fan in that of one expert. A chip that holds
+experts ``0..n-1`` of a layer then holds exactly the first ``n`` experts
+of the uncut model, whatever ``n`` is.
+
 Kinds of leaf (the ``init`` of a template entry):
   normal  truncated normal in [-2, 2], scaled by 1/sqrt(fan in) of one layer
   embed   normal, standard deviation 0.02
@@ -68,6 +74,20 @@ def draw_whole(key: jax.Array, name: str, shape, init: str) -> jax.Array:
     return draw(leaf_key(key, name), tuple(shape), init)
 
 
+def draw_expert(key: jax.Array, name: str, layer, expert, shape, init: str) -> jax.Array:
+    """Expert ``expert`` of layer ``layer`` (ints or traced scalars) of the
+    stacked expert leaf ``name``; ``shape`` is one expert's, without the
+    experts axis, so the fan in is one expert's."""
+    key = jax.random.fold_in(jax.random.fold_in(leaf_key(key, name), layer), expert)
+    return draw(key, tuple(shape), init)
+
+
+def _draw_experts(key, name, layer, shape, init, dt):
+    """All experts of one layer of an expert leaf, ``shape`` = (E, ...)."""
+    return jax.lax.map(lambda e: draw_expert(key, name, layer, e, shape[1:], init).astype(dt),
+                       jnp.arange(shape[0]))
+
+
 def _leaves(template, is_leaf):
     out = []
     for path, spec in jax.tree_util.tree_flatten_with_path(template, is_leaf=is_leaf)[0]:
@@ -88,7 +108,11 @@ def params_fn(template, default_dtype: str, *, is_leaf):
         out = []
         for name, spec in leaves:
             dt = jnp.dtype(spec.dtype or default_dtype)
-            if spec.axes and spec.axes[0] == "layers":
+            if tuple(spec.axes[:2]) == ("layers", "experts"):
+                arr = jax.lax.map(
+                    lambda l: _draw_experts(key, name, l, spec.shape[1:], spec.init, dt),
+                    jnp.arange(spec.shape[0]))
+            elif spec.axes and spec.axes[0] == "layers":
                 n, shape = spec.shape[0], spec.shape[1:]
                 arr = jax.lax.map(
                     lambda l: draw_layer(key, name, l, shape, spec.init).astype(dt),
